@@ -23,6 +23,9 @@ def main() -> None:
                     help="where BENCH_<section>.json files are written")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import bench_paper as bp
     from . import bench_kernels as bk
     from . import bench_multitenant as bm

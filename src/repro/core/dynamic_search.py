@@ -48,8 +48,9 @@ def hot_phase_graph(x_hot_pad, adj_hot_pad, hot_entries, queries, *,
 def hot_phase_mxu(x_hot, queries, *, pool_size: int, use_kernel: bool = False):
     """Phase 1, beyond-paper: exact brute-force over the (tiny) hot set.
 
-    On TPU this runs as the fused Pallas distance+top-k scorer at MXU peak;
-    on CPU (tests, benchmarks) the jnp reference path is used.
+    ``use_kernel`` routes it through the fused Pallas distance+top-k
+    scorer, which does not compile for a TPU yet; otherwise the jnp
+    reference scorer runs.
     """
     H = x_hot.shape[0]
     if use_kernel:

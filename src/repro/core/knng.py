@@ -30,7 +30,9 @@ def _knn_chunk(chunk: jnp.ndarray, x: jnp.ndarray, x_sq: jnp.ndarray,
                row0: jnp.ndarray, k: int):
     """Top-(k) neighbors of ``chunk`` rows against the full table ``x``."""
     # ||c - x||^2 = ||c||^2 - 2 c.x + ||x||^2 ; ||c||^2 is rank-constant.
-    dots = chunk @ x.T                                    # (C, n)
+    # HIGHEST: exact f32 dots on TPU too (the default is one bf16 pass)
+    dots = jnp.matmul(chunk, x.T,
+                      precision=jax.lax.Precision.HIGHEST)  # (C, n)
     d2 = x_sq[None, :] - 2.0 * dots                       # (C, n) + const
     # Mask self-matches by row id (exact duplicates of other rows are kept —
     # they are legitimate neighbors).

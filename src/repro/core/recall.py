@@ -13,7 +13,10 @@ __all__ = ["ground_truth", "recall_at_k"]
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def _gt_chunk(q: jnp.ndarray, x: jnp.ndarray, x_sq: jnp.ndarray, k: int):
-    d2 = x_sq[None, :] - 2.0 * (q @ x.T)
+    # HIGHEST: the TPU's default matmul precision is one bf16 pass, which
+    # would rank the exact neighbours by rounded distances.
+    dots = jnp.matmul(q, x.T, precision=jax.lax.Precision.HIGHEST)
+    d2 = x_sq[None, :] - 2.0 * dots
     _, idx = jax.lax.top_k(-d2, k)
     return idx.astype(jnp.int32)
 

@@ -42,8 +42,6 @@ def pipeline_forward(stage_fn: Callable, stage_params, x: jnp.ndarray,
     Returns the pipeline output (M, mb, ...) — numerically identical to
     applying the stages sequentially (validated in tests).
     """
-    from jax.experimental.shard_map import shard_map
-
     S = mesh.shape[axis]
     M = num_microbatches or x.shape[0]
     if x.shape[0] != M:
@@ -84,9 +82,9 @@ def pipeline_forward(stage_fn: Callable, stage_params, x: jnp.ndarray,
             outs = jax.lax.psum(outs * mask, axis)
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return fn(stage_params, x)

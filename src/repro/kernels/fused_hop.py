@@ -47,17 +47,6 @@ __all__ = ["fused_hop_pallas", "fused_hop_paged_pallas"]
 _INF32 = np.float32(3.0e38)
 
 
-def _compiler_params(pltpu):
-    """jax renamed TPUCompilerParams → CompilerParams; support both."""
-    cls = (getattr(pltpu, "CompilerParams", None)
-           or getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        raise RuntimeError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams "
-            "nor TPUCompilerParams — incompatible JAX version")
-    return cls
-
-
 def _hop_kernel(refs, *, pltpu, mode: str, has_tree: bool, has_live: bool,
                 bl: int, R: int, L: int, n: int, hops: int, max_hops: int,
                 k: int, eval_gap: int, add_step: int, tree_depth: int,
@@ -352,7 +341,7 @@ def fused_hop_pallas(hs: HopState, adj_pad, queries, live_pad, mode: str,
 
     lane_spec = lambda w: pl.BlockSpec((bl, w), lambda i: (i, 0))
     full_spec = lambda s: pl.BlockSpec(s, lambda i: (0, 0))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     inputs = [ids, dists, exp, seen, stat, q]
     in_specs = [lane_spec(L), lane_spec(L), lane_spec(L), lane_spec(n1),
@@ -411,7 +400,7 @@ def fused_hop_pallas(hs: HopState, adj_pad, queries, live_pad, mode: str,
             pltpu.SemaphoreType.DMA((bl,)),
             pltpu.SemaphoreType.DMA((2, R)),
         ],
-        compiler_params=_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*inputs)
@@ -472,7 +461,7 @@ def fused_hop_paged_pallas(hs: HopState, pt, adj_pad, queries, live_pad,
 
     lane_spec = lambda w: pl.BlockSpec((bl, w), lambda i: (i, 0))
     full_spec = lambda s: pl.BlockSpec(s, lambda i: (0, 0))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     inputs = [i32(hs.ids), hs.dists, i32(hs.expanded), i32(pt), stat,
               queries.astype(jnp.float32)]
@@ -539,7 +528,7 @@ def fused_hop_paged_pallas(hs: HopState, pt, adj_pad, queries, live_pad,
             pltpu.SemaphoreType.DMA((bl, ppl)),
         ],
         input_output_aliases={pool_idx: 4},
-        compiler_params=_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*inputs)

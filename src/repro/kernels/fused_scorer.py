@@ -26,17 +26,6 @@ from .bitonic import bitonic_sort_kv, next_pow2
 __all__ = ["fused_topk_l2_pallas"]
 
 
-def _compiler_params(pltpu):
-    """jax renamed TPUCompilerParams → CompilerParams; support both."""
-    cls = (getattr(pltpu, "CompilerParams", None)
-           or getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        raise RuntimeError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams "
-            "nor TPUCompilerParams — incompatible JAX version")
-    return cls
-
-
 def _scorer_kernel(q_ref, x_ref, od_ref, oi_ref, run_d, run_i, *,
                    k: int, bn: int, n_blocks: int, sort_len: int,
                    id_sentinel: int):
@@ -121,7 +110,7 @@ def fused_topk_l2_pallas(q: jnp.ndarray, x: jnp.ndarray, *, k: int,
             pltpu.VMEM((bq, k), jnp.float32),
             pltpu.VMEM((bq, k), jnp.int32),
         ],
-        compiler_params=_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qp, xp)

@@ -1,10 +1,14 @@
 """Jitted public wrappers for the Pallas kernels.
 
-Dispatch policy: on a TPU backend the compiled kernels run natively; on CPU
-(this container, unit tests) they run under ``interpret=True`` when
-explicitly requested and otherwise fall back to the jnp oracles in
-:mod:`repro.kernels.ref`, which XLA:CPU compiles well.  Either way the
-function contracts are identical — tests assert kernel ≡ ref.
+Dispatch policy: on a TPU backend the native kernels are attempted, with
+no fallback — and none of them compiles for the chip yet (Mosaic refuses
+``fused_hop`` at lowering and ``fused_topk_l2`` at layout inference; see
+``tests/test_tpu_compile.py``), so on a TPU only the composed path of the
+default ``DQFConfig`` (``fused=False``, ``hot_mode="graph"``) runs.  On CPU
+the kernels run under ``interpret=True`` when explicitly requested and
+otherwise fall back to the jnp oracles in :mod:`repro.kernels.ref`, which
+XLA:CPU compiles well.  The function contracts are identical — tests
+assert kernel ≡ ref in interpret mode.
 """
 
 from __future__ import annotations

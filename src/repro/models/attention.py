@@ -273,7 +273,6 @@ def gqa_decode(p, x1, cache: KVCache, pos: jnp.ndarray, *, cfg,
 def _flash_decode(q, k_new, v_new, cache: KVCache, pos, *, cfg, window,
                   mesh, model_axis: str = "model"):
     """Sequence-sharded decode attention (flash-decoding on the TP axis)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import _data_axes
 
@@ -325,14 +324,14 @@ def _flash_decode(q, k_new, v_new, cache: KVCache, pos, *, cfg, window,
         o = jnp.transpose(o, (0, 2, 1, 3)).astype(q.dtype)  # (B,1,H,hd)
         return o, ck, cv, cpos
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec), P(bspec), P(bspec),
                   P(bspec, model_axis), P(bspec, model_axis),
                   P(model_axis)),
         out_specs=(P(bspec), P(bspec, model_axis), P(bspec, model_axis),
                    P(model_axis)),
-        check_rep=False)
+        check_vma=False)
     o, ck, cv, cpos = fn(q, k_new, v_new, cache.k, cache.v, cache.pos)
     return o, KVCache(ck, cv, cpos)
 

@@ -116,8 +116,6 @@ def sharded_search(index: ShardedIndex, queries: np.ndarray, mesh: Mesh, *,
 
     queries shard over ``data_axis``; segments live on ``model_axis``.
     """
-    from jax.experimental.shard_map import shard_map
-
     S = index.num_shards
     if mesh.shape[model_axis] != S:
         raise ValueError(f"{S} shards need model axis of size {S}")
@@ -133,12 +131,12 @@ def sharded_search(index: ShardedIndex, queries: np.ndarray, mesh: Mesh, *,
         neg, idx = jax.lax.top_k(-all_d, k)
         return jnp.take_along_axis(all_ids, idx, axis=1), -neg
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(model_axis), P(model_axis), P(model_axis),
                   P(model_axis), P(data_axis)),
         out_specs=(P(data_axis), P(data_axis)),
-        check_rep=False)   # fresh while-loop carries are unvarying by design
+        check_vma=False)   # fresh while-loop carries are unvarying by design
     ids, dists = jax.jit(fn)(
         jnp.asarray(index.x_pad), jnp.asarray(index.adj_pad),
         jnp.asarray(index.entries), jnp.asarray(index.offsets),

@@ -3,6 +3,7 @@
 import numpy as np
 
 from repro.core.knng import build_knng, exact_knn, nn_descent
+from repro.core.recall import ground_truth
 from repro.core.ssg import (SSGParams, build_ssg, ensure_connected, medoid,
                             ssg_prune)
 from tests.conftest import make_clustered
@@ -21,6 +22,19 @@ def test_exact_knn_matches_bruteforce():
     # compare as sets per row (ties can permute)
     for a, b in zip(ids, want):
         assert set(a.tolist()) == set(b.tolist())
+
+
+def test_ground_truth_matches_float64_topk():
+    """The exact reference ranks by float64 distances: same ids, same
+    order, on clustered rows at SIFT width (d=128)."""
+    rng = np.random.default_rng(6)
+    x = make_clustered(n=700, d=128, seed=6)
+    q = x[rng.choice(700, 40, replace=False)] \
+        + 0.1 * rng.standard_normal((40, 128)).astype(np.float32)
+    x64, q64 = x.astype(np.float64), q.astype(np.float64)
+    d = np.sum((q64[:, None, :] - x64[None, :, :]) ** 2, -1)
+    want = np.argsort(d, 1, kind="stable")[:, :10]
+    np.testing.assert_array_equal(ground_truth(x, q, 10, chunk=16), want)
 
 
 def test_nn_descent_high_recall():
