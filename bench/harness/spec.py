@@ -1,0 +1,102 @@
+"""Finds a cell's configuration, traffic mix and metric readers by name.
+
+Everything that belongs to one configuration, one traffic mix or one
+per-layer metric sits in a file of its own:
+
+* ``BENCHMARK.json`` (checkout root) names the cells, and for each the
+  configuration and the traffic mix;
+* the configuration is the JSON file that ``BENCHMARK.json`` gives as its
+  ``file``;
+* the traffic mix is ``bench/traffic/<traffic>.json``;
+* a per-layer metric is ``bench/metrics/<metric name>.py``, a module with
+  one function ``read(run)`` that returns a number or None, and
+  ``TRACE_LOG = True`` if it reads the engine's per-query trace log,
+  which the traced run then turns on.
+
+A new cell, configuration, mix or metric is new files and new entries;
+no file here changes.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import importlib.util
+import json
+import os
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+
+__all__ = ["BENCH", "ROOT", "Cell", "load_cell", "load_reader",
+           "uses_trace_log", "enable_compile_cache"]
+
+
+def enable_compile_cache(root: str = ROOT) -> str:
+    """JAX's persistent compilation cache at ``<root>/.bench_cache/jax``,
+    a fixed place inside the checkout, for every program however short
+    its compile."""
+    import jax
+
+    path = os.path.join(root, ".bench_cache", "jax")
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path
+
+
+@dataclasses.dataclass
+class Cell:
+    name: str
+    chips: int
+    config: dict
+    traffic: dict
+    end_to_end: list        # BENCHMARK.json entries this cell reports
+    per_layer: list
+
+
+def _applies(metric: dict, cell: str) -> bool:
+    return "workloads" not in metric or cell in metric["workloads"]
+
+
+def _read_json(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def load_cell(name: str, root: str = ROOT) -> Cell:
+    """The cell ``name`` of ``<root>/BENCHMARK.json``; KeyError if none."""
+    spec = _read_json(os.path.join(root, "BENCHMARK.json"))
+    cells = {w["name"]: w for w in spec["workloads"]}
+    if name not in cells:
+        raise KeyError(f"no cell {name!r} in BENCHMARK.json "
+                       f"(cells: {', '.join(sorted(cells))})")
+    w = cells[name]
+    configs = {c["name"]: c for c in spec["configs"]}
+    config = _read_json(os.path.join(root, configs[w["config"]]["file"]))
+    traffic = _read_json(os.path.join(root, "bench", "traffic",
+                                      w["traffic"] + ".json"))
+    return Cell(name=name, chips=int(w["chips"]), config=config,
+                traffic=traffic,
+                end_to_end=[m for m in spec["end_to_end"]
+                            if _applies(m, name)],
+                per_layer=[m for m in spec["per_layer"]
+                           if _applies(m, name)])
+
+
+def _reader_module(metric: str, root: str):
+    path = os.path.join(root, "bench", "metrics", metric + ".py")
+    spec = importlib.util.spec_from_file_location(
+        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_reader(metric: str, root: str = ROOT):
+    """The ``read`` function of ``bench/metrics/<metric>.py``."""
+    return _reader_module(metric, root).read
+
+
+def uses_trace_log(metric: str, root: str = ROOT) -> bool:
+    """Whether the reader of ``metric`` needs the per-query trace log."""
+    return bool(getattr(_reader_module(metric, root), "TRACE_LOG", False))
