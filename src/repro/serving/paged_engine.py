@@ -34,7 +34,6 @@ last owns the scrape surface — stale twins are replaced, never merged).
 from __future__ import annotations
 
 import collections
-import contextlib
 import time
 from typing import Optional
 
@@ -48,7 +47,7 @@ from repro.core.dynamic_search import _seed_full_state, hot_phase_stacked
 from repro.core.features import feature_matrix, hot_features
 from repro.core.types import DQFConfig, HotFeatures, PoolState
 from repro.obs import (ObsConfig, PerfSentinel, Timeline, TraceLog,
-                       device_annotation, sample_decision)
+                       sample_decision)
 from repro.serving import paged as pg
 from repro.serving.engine import LATENCY_WINDOW, EngineStats, retire_batch
 from repro.serving.status import EngineConfig, QueryStatus, shed_victim
@@ -97,14 +96,16 @@ class PagedWaveEngine:
         self.registry = ((self.obs.registry
                           or getattr(dqf, "registry", None))
                          if obs_on else None)
-        self._tick_ann = ((lambda: device_annotation("dqf.paged_tick"))
-                          if obs_on else contextlib.nullcontext)
         self.timeline = Timeline(enabled=obs_on and self.obs.timeline,
                                  capacity=self.obs.timeline_capacity)
         self.traces = TraceLog(self.obs.trace_capacity)
         self._trace_rate = float(self.obs.trace_rate) if obs_on else 0.0
         self._trace_seed = int(self.obs.trace_seed)
         self._lane_trace: list = [None] * self.capacity
+        # (hot_stats, [(lane, row)]) of sampled admissions whose hot-phase
+        # counters are read after the next tick's fetch, when the hot
+        # phase has long completed, so the refill never waits on it
+        self._hot_pending: list = []
         if self.registry is not None:
             r = self.registry
             self._h_service = r.histogram(
@@ -266,6 +267,9 @@ class PagedWaveEngine:
                                      self.engine_cfg.shed_policy)
                 self._results[victim[0]] = self._terminal_result(
                     victim[3], QueryStatus.SHED)
+                self.timeline.async_span(
+                    "req.queued", victim[0], victim[2], now,
+                    tenant=victim[3], status=QueryStatus.SHED.value)
                 self.stats.shed += 1
                 self.stats.note_terminal(QueryStatus.SHED)
             else:
@@ -445,26 +449,31 @@ class PagedWaveEngine:
         phase; :func:`repro.serving.paged.admit_wave` scatters the seeded
         lanes device-side.  Requests whose tenant was evicted (or
         re-created — the ``gen`` check) while queued drop immediately.
+        Nothing here reads back from the device: sampled lanes' hot-phase
+        counters wait in ``_hot_pending`` for the next tick's fetch.
         """
+        tl = self.timeline
         reg = self.dqf.tenants
         free = self.pagepool.free_lane_count
         reqs = []
-        now = self._clock()
-        while self.queue and len(reqs) < free:
-            r = self.queue.popleft()
-            name, gen = r[3], r[4]
-            if name not in reg or reg.get(name).gen != gen:
-                self._results[r[0]] = self._terminal_result(
-                    name, QueryStatus.DROPPED)
-                self.stats.dropped += 1
-                self.stats.note_terminal(QueryStatus.DROPPED)
-            elif r[5] is not None and now >= r[5]:
-                self._results[r[0]] = self._terminal_result(
-                    name, QueryStatus.DEADLINE)
-                self.stats.deadline_hit += 1
-                self.stats.note_terminal(QueryStatus.DEADLINE)
-            else:
-                reqs.append(r)
+        with tl.span("refill.queue"):
+            now = self._clock()
+            while self.queue and len(reqs) < free:
+                r = self.queue.popleft()
+                name, gen = r[3], r[4]
+                if name not in reg or reg.get(name).gen != gen:
+                    status = QueryStatus.DROPPED
+                    self.stats.dropped += 1
+                elif r[5] is not None and now >= r[5]:
+                    status = QueryStatus.DEADLINE
+                    self.stats.deadline_hit += 1
+                else:
+                    reqs.append(r)
+                    continue
+                self._results[r[0]] = self._terminal_result(name, status)
+                self.stats.note_terminal(status)
+                tl.async_span("req.queued", r[0], r[2], now, tenant=name,
+                              status=status.value)
         if not reqs:
             return
         m = len(reqs)
@@ -476,61 +485,92 @@ class PagedWaveEngine:
             # again next tick — the requests stay live, never lost
             self.queue.extendleft(reversed(reqs))
             return
-        lanes_pad = np.full(mp, self.capacity, np.int32)
-        lanes_pad[:m] = lanes
-        pt_pad = self.pagepool.page_table[lanes_pad]
-        qs = np.zeros((mp, self._d), np.float32)
-        qs[:m] = np.stack([r[1] for r in reqs])
-        tidx = np.zeros(mp, np.int32)
-        tidx[:m] = [reg.slot_of(r[3]) for r in reqs]
-        stk = reg.stacked(self.dqf.store)
-        tidx_d = jnp.asarray(tidx)
-        q_d = jnp.asarray(qs)
-        hot_pool, hot_stats = self._hot_phase(
-            stk.x, stk.adj, stk.entries, stk.mask, tidx_d, q_d,
-            pool_size=self.cfg.hot_pool, max_hops=self.cfg.max_hops,
-            mode=self.cfg.hot_mode)
-        hf = hot_features(hot_pool, self.cfg.k)
-        seeded = _seed_full_state(hot_pool, stk.ids[tidx_d],
-                                  self.dqf.store.capacity,
-                                  self.cfg.full_pool,
-                                  self.dqf._dev["live_pad"])
-        admit_mask = np.zeros(mp, bool)
-        admit_mask[:m] = True
-        self._state = self._admit(
-            self._state, jnp.asarray(lanes_pad), jnp.asarray(pt_pad),
-            seeded, q_d, hf.first, hf.first_div_kth,
-            jnp.asarray(admit_mask), page_cols=self.page_cols)
-        # same sampling contract as the fixed engine: pure in (seed, rid),
-        # hot-phase stats transfer only when some admitted lane is sampled
-        sampled = [sample_decision(self._trace_seed, r[0], self._trace_rate)
-                   for r in reqs]
-        if any(sampled):
-            hot_hops = np.asarray(hot_stats.hops)
-            hot_dist = np.asarray(hot_stats.dist_count)
-        t_seed = self._clock()
-        for j, lane in enumerate(lanes):
-            lane = int(lane)
-            self._queries[lane] = reqs[j][1]
-            rid, t_in = reqs[j][0], reqs[j][2]
-            self._lane_meta[lane] = (rid, t_in, t_seed, reqs[j][3],
-                                     reqs[j][4], reqs[j][5])
-            self._lane_status[lane] = None
-            self._lane_degraded[lane] = False
-            wait_ms = (t_seed - t_in) * 1e3
-            self.stats.queue_wait_ms.append(wait_ms)
-            if self.registry is not None:
-                self._h_qwait.observe(wait_ms)
-            if sampled[j]:
-                self._lane_trace[lane] = {
-                    "rid": rid, "tenant": reqs[j][3],
-                    "hot_hops": int(hot_hops[j]),
-                    "hot_dist_evals": int(hot_dist[j]),
-                    "seed_tick": self.stats.ticks,
-                }
-            else:
-                self._lane_trace[lane] = None
-        self._table_key = None
+        with tl.span("refill.admit", admitted=m, bucket=mp):
+            lanes_pad = np.full(mp, self.capacity, np.int32)
+            lanes_pad[:m] = lanes
+            pt_pad = self.pagepool.page_table[lanes_pad]
+            qs = np.zeros((mp, self._d), np.float32)
+            qs[:m] = np.stack([r[1] for r in reqs])
+            tidx = np.zeros(mp, np.int32)
+            tidx[:m] = [reg.slot_of(r[3]) for r in reqs]
+            stk = reg.stacked(self.dqf.store)
+            tidx_d = jnp.asarray(tidx)
+            q_d = jnp.asarray(qs)
+            hot_pool, hot_stats = self._hot_phase(
+                stk.x, stk.adj, stk.entries, stk.mask, tidx_d, q_d,
+                pool_size=self.cfg.hot_pool, max_hops=self.cfg.max_hops,
+                mode=self.cfg.hot_mode)
+            hf = hot_features(hot_pool, self.cfg.k)
+            seeded = _seed_full_state(hot_pool, stk.ids[tidx_d],
+                                      self.dqf.store.capacity,
+                                      self.cfg.full_pool,
+                                      self.dqf._dev["live_pad"])
+            admit_mask = np.zeros(mp, bool)
+            admit_mask[:m] = True
+            self._state = self._admit(
+                self._state, jnp.asarray(lanes_pad), jnp.asarray(pt_pad),
+                seeded, q_d, hf.first, hf.first_div_kth,
+                jnp.asarray(admit_mask), page_cols=self.page_cols)
+        with tl.span("refill.lanes"):
+            # same sampling contract as the fixed engine: pure in (seed,
+            # rid); hot-phase stats transfer only when some admitted lane
+            # is sampled
+            sampled = [sample_decision(self._trace_seed, r[0],
+                                       self._trace_rate) for r in reqs]
+            if any(sampled):
+                self._hot_pending.append(
+                    (hot_stats, [(int(lanes[j]), j) for j in range(m)
+                                 if sampled[j]]))
+            t_seed = self._clock()
+            seed_tick = self.stats.ticks
+            for j, lane in enumerate(lanes):
+                lane = int(lane)
+                self._queries[lane] = reqs[j][1]
+                rid, t_in = reqs[j][0], reqs[j][2]
+                self._lane_meta[lane] = (rid, t_in, t_seed, reqs[j][3],
+                                         reqs[j][4], reqs[j][5], seed_tick)
+                self._lane_status[lane] = None
+                self._lane_degraded[lane] = False
+                wait_ms = (t_seed - t_in) * 1e3
+                self.stats.queue_wait_ms.append(wait_ms)
+                if self.registry is not None:
+                    self._h_qwait.observe(wait_ms)
+                if sampled[j]:
+                    self._lane_trace[lane] = {
+                        "rid": rid, "tenant": reqs[j][3],
+                        "hot_hops": None, "hot_dist_evals": None,
+                        "seed_tick": seed_tick,
+                    }
+                else:
+                    self._lane_trace[lane] = None
+                if tl.enabled:
+                    tl.async_span("req.queued", rid, t_in, t_seed,
+                                  tenant=reqs[j][3])
+            self._table_key = None
+
+    def _fetch(self, *arrays) -> list:
+        """Blocking device→host reads of tick outputs (``tick.fetch``)."""
+        tl = self.timeline
+        if not tl.enabled:
+            return [np.asarray(a) for a in arrays]
+        with tl.span("tick.fetch", arrays=len(arrays),
+                     bytes=int(sum(a.nbytes for a in arrays))):
+            return [np.asarray(a) for a in arrays]
+
+    def _read_hot_stats(self):
+        """Hot-phase counters of the lanes sampled at the last refill.
+
+        Called after a tick's fetch: that tick ran on the state the
+        admission wrote, so the hot phase before it has completed and
+        this read waits on nothing.
+        """
+        for hot_stats, rows in self._hot_pending:
+            hops, dist = self._fetch(hot_stats.hops, hot_stats.dist_count)
+            for lane, j in rows:
+                tr = self._lane_trace[lane]
+                tr["hot_hops"] = int(hops[j])
+                tr["hot_dist_evals"] = int(dist[j])
+        self._hot_pending.clear()
 
     def _terminal_result(self, tenant: str, status: QueryStatus) -> dict:
         k = self.cfg.k
@@ -611,17 +651,18 @@ class PagedWaveEngine:
                 table = self._bind_table(lanes_np)
                 with tl.span("tick.jit", bucket=len(lanes_np),
                              live=n_live):
-                    with self._tick_ann():
-                        (self._state,
-                         (act, hops_b, ids_b, dists_b)) = self._tick_fn(
-                            self._state, jnp.asarray(lanes_np),
-                            jnp.asarray(pt_np), table,
-                            self.dqf._dev["adj_pad"],
-                            self.dqf._dev["live_pad"])
-                        if tl.enabled:  # make the span cover device time
-                            jax.block_until_ready(self._state)
+                    (self._state,
+                     (act, hops_b, ids_b, dists_b)) = self._tick_fn(
+                        self._state, jnp.asarray(lanes_np),
+                        jnp.asarray(pt_np), table,
+                        self.dqf._dev["adj_pad"],
+                        self.dqf._dev["live_pad"])
+                    if tl.enabled:  # make the span cover device time
+                        jax.block_until_ready(self._state)
                 self.stats.ticks += 1
-                active = np.array(act)  # writable: deadlines clear it
+                (active,) = self._fetch(act)
+                active = active.copy()  # writable: deadlines clear it
+                self._read_hot_stats()
                 now = self._clock()
                 # degraded tier reads: host-fetch batch rows are bucket
                 # rows here — map them through lanes_np to lane slots
@@ -649,10 +690,11 @@ class PagedWaveEngine:
                 retiring = [j for j in range(n_live) if not active[j]
                             and self._lane_meta[lanes_np[j]] is not None]
                 if retiring:
+                    ids_h, dists_h, hops_h = self._fetch(ids_b, dists_b,
+                                                         hops_b)
                     with tl.span("tick.retire", retiring=len(retiring)):
-                        self._retire(lanes_np, retiring, np.asarray(ids_b),
-                                     np.asarray(dists_b),
-                                     np.asarray(hops_b), now)
+                        self._retire(lanes_np, retiring, ids_h, dists_h,
+                                     hops_h, now)
             else:
                 self.stats.ticks += 1
             if self.auto_compact and not self._draining \
@@ -673,61 +715,75 @@ class PagedWaveEngine:
                 ids_b: np.ndarray, dists_b: np.ndarray,
                 hops_b: np.ndarray, now: float):
         """Harvest results for retiring bucket rows, then free their lanes."""
+        tl = self.timeline
         rl = [int(lanes_np[j]) for j in retiring]
-        batch_ids, batch_dists = retire_batch(
-            self.dqf.store, self.dqf._rerank_k, self.cfg.k,
-            ids_b[retiring], dists_b[retiring], self._queries[rl])
+        with tl.span("retire.results"):
+            batch_ids, batch_dists = retire_batch(
+                self.dqf.store, self.dqf._rerank_k, self.cfg.k,
+                ids_b[retiring], dists_b[retiring], self._queries[rl])
         # sampled-lane stats transfer once per retiring tick, never per lane
         if any(self._lane_trace[ln] is not None for ln in rl):
-            dist_all = np.asarray(self._state.dist_count)
-            term_all = np.asarray(self._state.terminated)
-        for i, j in enumerate(retiring):
-            lane = rl[i]
-            rid, t_in, t_seed, tenant, gen, _ = self._lane_meta[lane]
-            ids, dists = batch_ids[i], batch_dists[i]
-            hops = int(hops_b[j])
-            degraded = self._lane_degraded[lane]
-            status = self._lane_status[lane] or (
-                QueryStatus.DEGRADED if degraded else QueryStatus.OK)
-            self._results[rid] = {"ids": ids, "dists": dists, "hops": hops,
-                                  "tenant": tenant,
-                                  "degraded": bool(degraded),
-                                  "status": status.value}
-            self.stats.completed += 1
-            self.stats.note_terminal(status)
-            if status is QueryStatus.DEADLINE:
-                self.stats.deadline_hit += 1
-            if degraded:
-                self.stats.degraded += 1
-            self.stats.total_hops += hops
-            straggled = hops >= self.cfg.max_hops
-            if straggled:
-                self.stats.straggled += 1
-            service_ms = (now - t_seed) * 1e3
-            self.stats.latencies_ms.append((now - t_in) * 1e3)
-            if self.registry is not None:
-                self._h_service.observe(service_ms)
-                self._h_hops.observe(hops)
-            tr = self._lane_trace[lane]
-            if tr is not None:
-                tr.update(
-                    queue_wait_ms=(t_seed - t_in) * 1e3,
-                    service_ms=service_ms,
-                    total_ms=(now - t_in) * 1e3,
-                    full_hops=hops,
-                    full_dist_evals=int(dist_all[lane]),
-                    terminated_early=bool(term_all[lane]),
-                    straggled=straggled,
-                    rerank_k=int(self.dqf._rerank_k),
-                    ticks_in_flight=self.stats.ticks - tr["seed_tick"],
-                    top_id=int(ids[0]))
-                self.traces.add(tr)
-                self._lane_trace[lane] = None
-            self._lane_meta[lane] = None
-            self._lane_status[lane] = None
-            self._lane_degraded[lane] = False
-            if tenant in self.dqf.tenants \
-                    and self.dqf.tenants.get(tenant).gen == gen:
-                self.dqf.record(ids[None, :], tenant=tenant)
-                self.dqf.maybe_rebuild_hot(tenant=tenant)
-        self.pagepool.free(rl)
+            dist_all, term_all = self._fetch(self._state.dist_count,
+                                             self._state.terminated)
+        with tl.span("retire.lanes"):
+            for i, j in enumerate(retiring):
+                lane = rl[i]
+                (rid, t_in, t_seed, tenant, gen, _,
+                 seed_tick) = self._lane_meta[lane]
+                ids, dists = batch_ids[i], batch_dists[i]
+                hops = int(hops_b[j])
+                degraded = self._lane_degraded[lane]
+                status = self._lane_status[lane] or (
+                    QueryStatus.DEGRADED if degraded else QueryStatus.OK)
+                self._results[rid] = {"ids": ids, "dists": dists,
+                                      "hops": hops, "tenant": tenant,
+                                      "degraded": bool(degraded),
+                                      "status": status.value}
+                self.stats.completed += 1
+                self.stats.note_terminal(status)
+                if status is QueryStatus.DEADLINE:
+                    self.stats.deadline_hit += 1
+                if degraded:
+                    self.stats.degraded += 1
+                self.stats.total_hops += hops
+                straggled = hops >= self.cfg.max_hops
+                if straggled:
+                    self.stats.straggled += 1
+                service_ms = (now - t_seed) * 1e3
+                self.stats.latencies_ms.append((now - t_in) * 1e3)
+                if self.registry is not None:
+                    self._h_service.observe(service_ms)
+                    self._h_hops.observe(hops)
+                if tl.enabled:
+                    tl.async_span("req.lane", rid, t_seed, now,
+                                  tenant=tenant, status=status.value,
+                                  ticks=self.stats.ticks - seed_tick)
+                tr = self._lane_trace[lane]
+                if tr is not None:
+                    tr.update(
+                        queue_wait_ms=(t_seed - t_in) * 1e3,
+                        service_ms=service_ms,
+                        total_ms=(now - t_in) * 1e3,
+                        full_hops=hops,
+                        full_dist_evals=int(dist_all[lane]),
+                        terminated_early=bool(term_all[lane]),
+                        straggled=straggled,
+                        rerank_k=int(self.dqf._rerank_k),
+                        ticks_in_flight=self.stats.ticks - seed_tick,
+                        top_id=int(ids[0]))
+                    self.traces.add(tr)
+                    self._lane_trace[lane] = None
+                self._lane_meta[lane] = None
+                self._lane_status[lane] = None
+                self._lane_degraded[lane] = False
+                t = self.dqf.tenants.get(tenant) \
+                    if tenant in self.dqf.tenants else None
+                if t is not None and t.gen == gen:
+                    self.dqf.record(ids[None, :], tenant=tenant)
+                    if t.counter.due:  # Alg 2's trigger
+                        with tl.span("hot.rebuild", tenant=tenant) as sp:
+                            hot = self.dqf.rebuild_hot(tenant=tenant)
+                            if tl.enabled:
+                                sp.args.update(hot_rows=hot.size,
+                                               version=hot.version)
+            self.pagepool.free(rl)
