@@ -50,11 +50,14 @@ import jax.numpy as jnp
 
 from repro.core import beam_search as bs
 from repro.core.beam_search import _merge_pool
+from repro.core.dynamic_search import _seed_full_state
+from repro.core.features import hot_features
 from repro.core.types import INF_DIST, PoolState, SearchStats
 
 __all__ = ["PagedState", "PagePool", "PageAllocDenied", "expand_step_paged",
-           "gather_wave", "scatter_wave", "admit_wave", "dense_seen",
-           "bucket_width", "zero_paged_state", "DEFAULT_PAGE_COLS"]
+           "gather_wave", "scatter_wave", "admit_wave", "seed_admit",
+           "dense_seen", "bucket_width", "zero_paged_state",
+           "DEFAULT_PAGE_COLS"]
 
 DEFAULT_PAGE_COLS = 256          # bools per seen page (must be a power of 2)
 MIN_BUCKET = 8                   # smallest gather-bucket width
@@ -456,6 +459,34 @@ def admit_wave(ps: PagedState, lanes: jnp.ndarray, pt: jnp.ndarray,
         hot_ratio=ps.hot_ratio.at[lanes].set(hot_ratio),
         seen_pages=ps.seen_pages.at[pt].set(pages),
     )
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("k", "pool_size", "page_cols"))
+def seed_admit(ps: PagedState, hot_pool: PoolState, hot_ids: jnp.ndarray,
+               tenant_idx: jnp.ndarray, queries: jnp.ndarray,
+               live_pad: jnp.ndarray, lanes_pt: jnp.ndarray, *, k: int,
+               pool_size: int, page_cols: int) -> PagedState:
+    """Seed an admission bucket and scatter it into the lanes, as one program.
+
+    Runs :func:`repro.core.features.hot_features`, the local→global map
+    ``hot_ids[tenant_idx]`` of the stacked ``(T, H+1)`` id table,
+    :func:`repro.core.dynamic_search._seed_full_state` and
+    :func:`admit_wave` in one trace, so an admission is one dispatch after
+    the hot phase instead of one per op.  ``hot_pool`` is the stacked hot
+    phase's local-id pool for the bucket.  ``lanes_pt`` packs each bucket
+    entry's lane slot (column 0) and page-table row (the other columns)
+    into one int32 array.  Padding entries target the scratch lane ``P``,
+    so the admission mask is ``lanes < P``; the sentinel id is
+    ``live_pad``'s last row.
+    """
+    lanes, pt = lanes_pt[:, 0], lanes_pt[:, 1:]
+    P = ps.active.shape[0] - 1
+    hf = hot_features(hot_pool, k)
+    seeded = _seed_full_state(hot_pool, hot_ids[tenant_idx],
+                              live_pad.shape[0] - 1, pool_size, live_pad)
+    return admit_wave(ps, lanes, pt, seeded, queries, hf.first,
+                      hf.first_div_kth, lanes < P, page_cols=page_cols)
 
 
 @functools.partial(jax.jit, static_argnames=("n1",))

@@ -16,10 +16,11 @@ paged attention restructures ragged KV (:mod:`repro.serving.paged`):
   O(log capacity) tick executables), advances it ``tick_hops``
   expansions — composed scan or the paged fused megakernel — and
   scatters the bucket back.  Work tracks live lanes, not capacity;
-* admission and retirement are device ``.at[]`` scatters
-  (:func:`repro.serving.paged.admit_wave`), never a host round-trip of
-  wave state, so lanes stream in and out continuously and a straggler
-  holds one lane slot, not a wave;
+* admission is two device programs, the stacked hot phase and
+  :func:`repro.serving.paged.seed_admit` (hot features, full-state
+  seeding and the ``.at[]`` scatter into the lanes); retirement frees
+  slots on the host.  Neither round-trips wave state, so lanes stream in
+  and out continuously and a straggler holds one lane slot, not a wave;
 * with a tiered store, block pins follow the allocator's *pages*: the
   pin set each tick is derived from the page-table-live lanes only, so a
   retired lane's blocks become evictable the moment its pages free.
@@ -43,8 +44,8 @@ import jax.numpy as jnp
 
 from repro.core import beam_search as bs
 from repro.core.decision_tree import predict_jax
-from repro.core.dynamic_search import _seed_full_state, hot_phase_stacked
-from repro.core.features import feature_matrix, hot_features
+from repro.core.dynamic_search import hot_phase_stacked
+from repro.core.features import feature_matrix
 from repro.core.types import DQFConfig, HotFeatures, PoolState
 from repro.obs import (ObsConfig, PerfSentinel, Timeline, TraceLog,
                        sample_decision)
@@ -130,7 +131,7 @@ class PagedWaveEngine:
                                     registry=self.registry, name="paged")
         self._tick_fn = self._build_tick()
         self._hot_phase = hot_phase_stacked
-        self._admit = pg.admit_wave
+        self._admit = pg.seed_admit
         # Perf sentinel (ISSUE 9).  The paged tick's compile schedule is
         # the pow2 bucket ladder — min_bucket, 2·min_bucket, …,
         # next_pow2(capacity) — so its executable budget is declared up
@@ -143,7 +144,7 @@ class PagedWaveEngine:
             self._tick_fn = self.sentinel.wrap("paged_tick", self._tick_fn)
             self._hot_phase = self.sentinel.wrap("hot_phase_stacked",
                                                  hot_phase_stacked)
-            self._admit = self.sentinel.wrap("paged_admit", pg.admit_wave)
+            self._admit = self.sentinel.wrap("paged_admit", pg.seed_admit)
             self.sentinel.expect("paged_tick", self._n_widths)
             self.sentinel.attach_capture(
                 self, capture_ticks=self.obs.capture_ticks,
@@ -445,12 +446,15 @@ class PagedWaveEngine:
         """Admit queued requests into freshly allocated lanes.
 
         The admission batch is padded to a power-of-two bucket (compile
-        keys match the tick's) and seeded with the stacked-tenant hot
-        phase; :func:`repro.serving.paged.admit_wave` scatters the seeded
-        lanes device-side.  Requests whose tenant was evicted (or
-        re-created — the ``gen`` check) while queued drop immediately.
-        Nothing here reads back from the device: sampled lanes' hot-phase
-        counters wait in ``_hot_pending`` for the next tick's fetch.
+        keys match the tick's) and runs as two device programs: the
+        stacked-tenant hot phase, then :func:`repro.serving.paged.seed_admit`,
+        which seeds the full phase and scatters the lanes device-side.
+        The host copies three arrays: tenant slots, queries, and the
+        padded lanes packed with their page-table rows.  Requests whose
+        tenant was evicted (or re-created — the ``gen`` check) while
+        queued drop immediately.  Nothing here reads back from the
+        device: sampled lanes' hot-phase counters wait in
+        ``_hot_pending`` for the next tick's fetch.
         """
         tl = self.timeline
         reg = self.dqf.tenants
@@ -488,7 +492,9 @@ class PagedWaveEngine:
         with tl.span("refill.admit", admitted=m, bucket=mp):
             lanes_pad = np.full(mp, self.capacity, np.int32)
             lanes_pad[:m] = lanes
-            pt_pad = self.pagepool.page_table[lanes_pad]
+            lanes_pt = np.concatenate(
+                [lanes_pad[:, None], self.pagepool.page_table[lanes_pad]],
+                axis=1)
             qs = np.zeros((mp, self._d), np.float32)
             qs[:m] = np.stack([r[1] for r in reqs])
             tidx = np.zeros(mp, np.int32)
@@ -500,17 +506,11 @@ class PagedWaveEngine:
                 stk.x, stk.adj, stk.entries, stk.mask, tidx_d, q_d,
                 pool_size=self.cfg.hot_pool, max_hops=self.cfg.max_hops,
                 mode=self.cfg.hot_mode)
-            hf = hot_features(hot_pool, self.cfg.k)
-            seeded = _seed_full_state(hot_pool, stk.ids[tidx_d],
-                                      self.dqf.store.capacity,
-                                      self.cfg.full_pool,
-                                      self.dqf._dev["live_pad"])
-            admit_mask = np.zeros(mp, bool)
-            admit_mask[:m] = True
             self._state = self._admit(
-                self._state, jnp.asarray(lanes_pad), jnp.asarray(pt_pad),
-                seeded, q_d, hf.first, hf.first_div_kth,
-                jnp.asarray(admit_mask), page_cols=self.page_cols)
+                self._state, hot_pool, stk.ids, tidx_d, q_d,
+                self.dqf._dev["live_pad"], jnp.asarray(lanes_pt),
+                k=self.cfg.k, pool_size=self.cfg.full_pool,
+                page_cols=self.page_cols)
         with tl.span("refill.lanes"):
             # same sampling contract as the fixed engine: pure in (seed,
             # rid); hot-phase stats transfer only when some admitted lane
