@@ -18,7 +18,7 @@ import time
 import numpy as np
 
 from . import reference, roofline, trace
-from .spec import Cell, load_reader, uses_trace_log
+from .spec import Cell, dqf_config, load_reader, uses_trace_log
 from .workload import ZipfWorkload, make_rows, seed_rng
 
 __all__ = ["run", "deploy", "Deployment", "Book"]
@@ -126,7 +126,6 @@ class Run:
     """What a per-layer metric reader gets."""
 
     cell: Cell
-    d: int
     degree: int
     peaks: dict
     book: Book
@@ -151,16 +150,19 @@ class Deployment:
 
 def deploy(cell: Cell, log) -> Deployment:
     """Set-up before any traffic of the window: everything here comes
-    from the configuration's ``data_seed``, none of it from ``--seed``."""
-    from repro.core import DQF, DQFConfig
+    from the configuration's ``data_seed``, none of it from ``--seed``.
+    The program's config is built first, so a bad ``dqf`` block fails
+    before the rows are made."""
+    from repro.core import DQF
 
     cfg, tr = cell.config, cell.traffic
+    dqf_cfg = dqf_config(cfg)
     g, ds = cfg["generator"], cfg["data_seed"]
     rows = make_rows(cfg["rows"], cfg["dim"], g["latent"], g["clusters"],
                      g["center_scale"], g["noise"], seed_rng(ds, 0))
     wl = ZipfWorkload(rows, tr["beta"], tr["sigma"], seed_rng(ds, 1))
     t = clock()
-    dqf = DQF(DQFConfig(k=cfg["guarantee"]["k"], **cfg["dqf"])).build(rows)
+    dqf = DQF(dqf_cfg).build(rows)
     build_s = clock() - t
     log(f"build: {build_s:.3f} s ({dqf.timings.full_build:.3f} s in "
         f"build_ssg)")
@@ -189,11 +191,13 @@ def _warm_up(eng, dqf, stream, sizes) -> None:
     eng._results.clear()
     # An Alg-2 rebuild draws the hot graph's entry points with
     # np.unique, so one rebuild in about thirty holds n_entry - 1 of them
-    # and the stacked tables change shape: compile that shape too.
+    # and the stacked tables change shape: compile both widths at every
+    # size.  The rebuild above may have changed the width in place too,
+    # after the traffic of every size but the last, so compile that one.
     stk = dqf.tenants.stacked(dqf.store)
     c = dqf.cfg
     e = stk.entries.shape[1]
-    for width in {c.n_entry, c.n_entry - 1} - {e}:
+    for width in {c.n_entry, c.n_entry - 1, e}:
         if width < 1:
             continue
         ent = np.full((stk.entries.shape[0], width), stk.x.shape[1] - 1,
@@ -434,8 +438,7 @@ def _per_layer(cell, eng, book, t0, t_end, tracer, device_kind):
     off = trace.clock_offset_ns(tr["host"], tracer.sync)
     lo, hi = tracer.lo * 1e9 + off, tracer.hi * 1e9 + off
     traces = {t["rid"]: t for t in eng.traces.snapshot()}
-    cfg = cell.config
-    run = Run(cell=cell, d=cfg["dim"], degree=eng.cfg.out_degree,
+    run = Run(cell=cell, degree=eng.cfg.out_degree,
         peaks=roofline.peaks(device_kind), book=book,
         window=(t0, t_end), traces=traces, timeline=eng.timeline.events(),
         ops=tr["ops"], stretch=(lo, hi), work=_work(book, tracer, traces))

@@ -9,9 +9,9 @@ from __future__ import annotations
 import json
 import os
 
-from .spec import BENCH
+from .spec import BENCH, dqf_config
 
-__all__ = ["peaks", "hop_bytes"]
+__all__ = ["peaks", "row_bytes", "hop_bytes"]
 
 
 def peaks(device_kind: str) -> dict:
@@ -23,9 +23,26 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def hop_bytes(dist_evals: int, hops: int, d: int, degree: int) -> int:
+def row_bytes(config: dict) -> int:
+    """HBM bytes the hop reads for one scored row of the configuration's
+    Full Index, by the width of what it stores: ``dim`` float32 without a
+    ``quant`` block; ``dim`` one-byte codes under ``sq8`` (its scale and
+    offset are one row for all, not a read per row); ``pq_m`` one-byte
+    codes under ``pq`` (its lookup tables are per query).  A tiered Full
+    Index raises: its rows come through a host fetch, which no HBM
+    roofline reckons."""
+    c = dqf_config(config)
+    if c.tier.mode != "none":
+        raise ValueError(f"no HBM row bytes for tier mode {c.tier.mode!r}")
+    d = int(config["dim"])
+    return {"none": d * 4, "sq8": d, "pq": c.quant.pq_m}[c.quant.mode]
+
+
+def hop_bytes(dist_evals: int, hops: int, row_bytes: int,
+              degree: int) -> int:
     """HBM bytes a beam-search hop needs at the least: each scored row
-    read once (``d`` float32) and each expanded node's adjacency row read
-    once (``degree`` int32).  Counted from the algorithm's own counters,
-    so it is the same whatever implements the hop."""
-    return int(dist_evals) * d * 4 + int(hops) * degree * 4
+    read once (``row_bytes``, from :func:`row_bytes`) and each expanded
+    node's adjacency row read once (``degree`` int32).  Counted from the
+    algorithm's own counters, so it is the same whatever implements the
+    hop."""
+    return int(dist_evals) * int(row_bytes) + int(hops) * degree * 4

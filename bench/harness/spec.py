@@ -6,7 +6,12 @@ per-layer metric sits in a file of its own:
 * ``BENCHMARK.json`` (checkout root) names the cells, and for each the
   configuration and the traffic mix;
 * the configuration is the JSON file that ``BENCHMARK.json`` gives as its
-  ``file``;
+  ``file``.  Its ``dqf`` block is the keyword arguments of the program's
+  ``DQFConfig``; where a field of ``DQFConfig`` is itself a dataclass
+  (``quant``, ``tier``), the block may give it as a nested block, which
+  :func:`dqf_config` builds into that dataclass.  Its ``metric`` and
+  ``dtype`` are checked against what the reference implements (squared
+  L2 over float32 rows);
 * the traffic mix is ``bench/traffic/<traffic>.json``;
 * a per-layer metric is ``bench/metrics/<metric name>.py``, a module with
   one function ``read(run)`` that returns a number or None, and
@@ -28,7 +33,10 @@ BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
 
 __all__ = ["BENCH", "ROOT", "Cell", "load_cell", "load_reader",
-           "uses_trace_log", "enable_compile_cache"]
+           "uses_trace_log", "enable_compile_cache", "dqf_config"]
+
+# what the reference (``reference.py``) and the row generator implement
+REFERENCE_SEMANTICS = {"metric": "l2", "dtype": "float32"}
 
 
 def enable_compile_cache(root: str = ROOT) -> str:
@@ -64,7 +72,9 @@ def _read_json(path: str) -> dict:
 
 
 def load_cell(name: str, root: str = ROOT) -> Cell:
-    """The cell ``name`` of ``<root>/BENCHMARK.json``; KeyError if none."""
+    """The cell ``name`` of ``<root>/BENCHMARK.json``; KeyError if none,
+    ValueError if its configuration states a ``metric`` or ``dtype`` that
+    the reference does not implement."""
     spec = _read_json(os.path.join(root, "BENCHMARK.json"))
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
@@ -73,6 +83,12 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     w = cells[name]
     configs = {c["name"]: c for c in spec["configs"]}
     config = _read_json(os.path.join(root, configs[w["config"]]["file"]))
+    for field, want in REFERENCE_SEMANTICS.items():
+        if config.get(field) != want:
+            raise ValueError(
+                f"configuration {w['config']!r}: {field} "
+                f"{config.get(field)!r} is not {want!r}, the only one the "
+                f"reference implements")
     traffic = _read_json(os.path.join(root, "bench", "traffic",
                                       w["traffic"] + ".json"))
     return Cell(name=name, chips=int(w["chips"]), config=config,
@@ -81,6 +97,35 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
                             if _applies(m, name)],
                 per_layer=[m for m in spec["per_layer"]
                            if _applies(m, name)])
+
+
+def dqf_config(config: dict):
+    """The program's ``DQFConfig`` for a configuration: its ``dqf`` block,
+    with ``k`` from its ``guarantee``.  A nested block becomes the
+    dataclass that its field's default is an instance of.  A key that
+    the dataclass lacks raises ValueError, naming both."""
+    from repro.core import DQFConfig
+
+    block = config["dqf"]
+    if "k" in block:
+        raise ValueError("dqf: key 'k' is given by guarantee.k")
+    return _dataclass(DQFConfig, dict(block, k=config["guarantee"]["k"]),
+                      "dqf")
+
+
+def _dataclass(cls, block: dict, where: str):
+    fields = {f.name: f for f in dataclasses.fields(cls)}
+    unknown = sorted(set(block) - set(fields))
+    if unknown:
+        raise ValueError(f"{where}: {cls.__name__} has no field "
+                         f"{', '.join(map(repr, unknown))}")
+    kw = {}
+    for name, value in block.items():
+        default = fields[name].default
+        if isinstance(value, dict) and dataclasses.is_dataclass(default):
+            value = _dataclass(type(default), value, f"{where}.{name}")
+        kw[name] = value
+    return cls(**kw)
 
 
 def _reader_module(metric: str, root: str):

@@ -7,10 +7,12 @@ collects only ``tests/``):
 
 They cover the trace-to-metrics reduction on a recorded trace, the
 latency of the open loop from each request's due time, the lookup of
-cells, mixes and metric readers by name, the refusal to run without a
-TPU, and the comparison that decides ``correct``: it passes the program
-at a small size and fails the control and each fault the cells can
-have.
+cells, mixes and metric readers by name, the program's config built from
+a configuration's blocks (nested ones too, unknown keys refused before
+any rows are made), the bytes a hop reads by stored code width, the
+refusal to run without a TPU, and the comparison that decides
+``correct``: it passes the program at a small size, float32 and ``sq8``,
+and fails the control and each fault the cells can have.
 """
 
 from __future__ import annotations
@@ -32,7 +34,8 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 
 from harness import cell as hc  # noqa: E402
 from harness import reference, roofline, trace  # noqa: E402
-from harness.spec import load_cell, load_reader, uses_trace_log  # noqa: E402
+from harness.spec import (dqf_config, load_cell, load_reader,  # noqa: E402
+                          uses_trace_log)
 from harness.workload import ZipfWorkload, make_rows, seed_rng  # noqa: E402
 
 DATA = os.path.join(BENCH, "tests", "data")
@@ -91,6 +94,69 @@ def test_a_new_cell_is_new_files_and_entries_only(tmp_path):
     assert read(_Run()) == 0
     with pytest.raises(KeyError):
         load_cell("no-such-cell", str(tmp_path))
+
+
+# ------------------------------------------- the configuration's dqf block
+def _config(**dqf):
+    return dict(load_cell("sift128-zipf-sat").config, dqf=dqf)
+
+
+def test_the_sift_config_builds_the_dqf_config_of_before():
+    from repro.core import DQFConfig
+    assert dqf_config(load_cell("sift128-zipf-sat").config) \
+        == DQFConfig(k=10, full_pool=192)
+
+
+def test_a_tier_block_becomes_a_tier_config():
+    from repro.tiering import TierConfig
+    c = dqf_config(_config(tier={"mode": "host", "block_rows": 128}))
+    assert c.tier == TierConfig(mode="host", block_rows=128)
+
+
+@pytest.mark.parametrize("dqf, names", [
+    ({"full_pool": 192, "ful_pool": 64}, ("dqf", "DQFConfig", "'ful_pool'")),
+    ({"quant": {"mode": "sq8", "rerank": 64}},
+     ("dqf.quant", "QuantConfig", "'rerank'")),
+], ids=["top_level", "nested"])
+def test_an_unknown_key_fails_before_the_rows_are_made(dqf, names,
+                                                       monkeypatch):
+    def no_rows(*_a, **_k):
+        raise AssertionError("make_rows reached")
+    monkeypatch.setattr(hc, "make_rows", no_rows)
+    cell = load_cell("sift128-zipf-sat")
+    cell.config = dict(cell.config, dqf=dqf)
+    with pytest.raises(ValueError) as e:
+        hc.deploy(cell, lambda *_: None)
+    for name in names:
+        assert name in str(e.value)
+
+
+@pytest.mark.parametrize("field, value", [("metric", "ip"),
+                                          ("dtype", "uint8")])
+def test_load_cell_refuses_semantics_the_reference_lacks(field, value,
+                                                         tmp_path):
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    path = tmp_path / "bench" / "configs" / "sift128-l2-50k.json"
+    path.write_text(json.dumps(dict(json.loads(path.read_text()),
+                                    **{field: value})))
+    with pytest.raises(ValueError, match=field):
+        load_cell("sift128-zipf-sat", str(tmp_path))
+
+
+@pytest.mark.parametrize("dim, dqf, expected", [
+    (128, {"full_pool": 192}, 512),
+    (960, {"quant": {"mode": "sq8", "rerank_k": 64}}, 960),
+    (128, {"quant": {"mode": "pq", "pq_m": 8}}, 8),
+], ids=["sift128", "sq8_960", "pq_m8"])
+def test_row_bytes_by_stored_code_width(dim, dqf, expected):
+    assert roofline.row_bytes(dict(_config(**dqf), dim=dim)) == expected
+
+
+def test_row_bytes_refuses_a_tiered_full_index():
+    with pytest.raises(ValueError, match="host"):
+        roofline.row_bytes(_config(tier={"mode": "host"}))
 
 
 # ----------------------------------------------------------- refusing to run
@@ -215,7 +281,8 @@ def test_paced_readers_are_their_sat_twins_and_only_some_need_the_log():
 
 
 def test_hop_bytes_and_peaks():
-    assert roofline.hop_bytes(10, 2, 128, 32) == 10 * 128 * 4 + 2 * 32 * 4
+    assert roofline.hop_bytes(10, 2, 128 * 4, 32) \
+        == 10 * 128 * 4 + 2 * 32 * 4
     assert roofline.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError):
         roofline.peaks("cpu")
@@ -343,6 +410,41 @@ def test_program_passes_and_each_control_fails():
     for precision, checks in out["controls"].items():
         failed = [k for k, c in checks.items() if c["value"] > c["limit"]]
         assert failed == ["dist_gap"], (precision, checks)
+
+
+def test_a_quantized_deployment_runs_correct():
+    from repro.core import QuantConfig
+    cell = _tiny()
+    cell.config = dict(cell.config, dim=960,
+                       dqf={"full_pool": 192,
+                            "quant": {"mode": "sq8", "rerank_k": 64}})
+    dep = hc.deploy(cell, lambda *_: None)
+    quant = dep.dqf.cfg.quant
+    assert isinstance(quant, QuantConfig) and quant.mode == "sq8"
+    out = _run(cell, deployment=dep)
+    assert out["correct"], out["checks"]
+    assert out["checks"]["dist_gap"]["value"] <= 1e-4
+    assert out["attempted"] > 100 and out["failed"] == 0
+
+
+def test_nothing_compiles_in_the_window_after_a_narrower_rebuild(
+        monkeypatch):
+    # the warm-up's own Alg-2 rebuild draws fewer entry points than the
+    # index it replaces, as one rebuild in about thirty does, so the
+    # stacked tables change width: the window still compiles nothing
+    import repro.core.dqf as dqf_mod
+    cell = _tiny()
+    dep = hc.deploy(cell, lambda *_: None)
+    before = dep.dqf.tenants.stacked(dep.dqf.store).entries.shape[1]
+    build = dqf_mod.build_hot_index
+
+    def narrower(*args, n_entry, **kw):
+        return build(*args, n_entry=n_entry // 2, **kw)
+    monkeypatch.setattr(dqf_mod, "build_hot_index", narrower)
+    out = _run(cell, deployment=dep)
+    assert dep.dqf.tenants.stacked(dep.dqf.store).entries.shape[1] < before
+    assert out["correct"], out["checks"]
+    assert out["window"]["compiles_in_window"] == 0
 
 
 def _stuck(eng):
