@@ -78,7 +78,8 @@ LATENCY_WINDOW = 4096
 
 
 def retire_batch(store, rerank_k: int, k: int, pool_ids: np.ndarray,
-                 pool_dists: np.ndarray, queries: np.ndarray):
+                 pool_dists: np.ndarray, queries: np.ndarray, *,
+                 timeline: Timeline, stats: "EngineStats"):
     """Final results for a batch of retiring lanes (host side).
 
     Drops sentinel/padding ids and rows tombstoned while the lanes were
@@ -86,6 +87,11 @@ def retire_batch(store, rerank_k: int, k: int, pool_ids: np.ndarray,
     are re-scored exactly in float32.  One vectorized pass covers every
     retiring lane — ``(m, L)`` pools in, ``(m, k)`` results out.  Shared
     by the fixed-wave and paged engines.
+
+    The re-scoring (gather, float32 sums, re-sort) is timed as the
+    ``retire.rerank`` span of ``timeline``, its ``rows`` arg the rows
+    re-scored, ``m * min(max(rerank_k, k), L)``, which ``stats.rerank_rows``
+    also counts; without a rerank neither is touched.
     """
     st = store
     m, L = pool_ids.shape
@@ -99,13 +105,16 @@ def retire_batch(store, rerank_k: int, k: int, pool_ids: np.ndarray,
     cd = np.take_along_axis(pool_dists, order, 1)[:, :rr]
     valid = np.take_along_axis(keep, order, 1)[:, :rr]
     if rerank_k:
-        safe = np.where(valid, cand, 0)
-        cd = np.sum((st.x[safe] - queries[:, None, :]) ** 2, axis=-1)
-        cd[~valid] = np.inf
-        top = np.argsort(cd, axis=1, kind="stable")[:, :k]
-        ids = np.take_along_axis(cand, top, 1)
-        dists = np.take_along_axis(cd, top, 1)
-        valid = np.take_along_axis(valid, top, 1)
+        rows = m * rr
+        stats.rerank_rows += rows
+        with timeline.span("retire.rerank", rows=rows):
+            safe = np.where(valid, cand, 0)
+            cd = np.sum((st.x[safe] - queries[:, None, :]) ** 2, axis=-1)
+            cd[~valid] = np.inf
+            top = np.argsort(cd, axis=1, kind="stable")[:, :k]
+            ids = np.take_along_axis(cand, top, 1)
+            dists = np.take_along_axis(cd, top, 1)
+            valid = np.take_along_axis(valid, top, 1)
     else:                                   # pools are sorted already
         ids, dists, valid = cand[:, :k], cd[:, :k], valid[:, :k]
     if ids.shape[1] < k:                    # rr < k: pad the tail
@@ -159,6 +168,7 @@ class EngineStats:
     ticks: int = 0
     total_hops: int = 0
     compactions: int = 0        # background drain-and-compact cycles
+    rerank_rows: int = 0        # rows re-scored in float32 at retirement
     # terminal-status tallies keyed by QueryStatus value — the single
     # source for engine_terminal_status_total{status=...}
     terminal: dict = dataclasses.field(default_factory=dict)
@@ -460,6 +470,7 @@ class WaveEngine:
                "engine_ticks_total": float(s.ticks),
                "engine_hops_total": float(s.total_hops),
                "engine_compactions_total": float(s.compactions),
+               "engine_rerank_rows_total": float(s.rerank_rows),
                "engine_queue_depth": float(len(self.queue)),
                "engine_live_lanes": float(
                    sum(m is not None for m in self._lane_meta)),
@@ -662,7 +673,8 @@ class WaveEngine:
                       queries: np.ndarray):
         """Final results for all lanes retiring this tick (host side)."""
         return retire_batch(self.dqf.store, self.dqf._rerank_k, self.cfg.k,
-                            pool_ids, pool_dists, queries)
+                            pool_ids, pool_dists, queries,
+                            timeline=self.timeline, stats=self.stats)
 
     def _tier_begin_tick(self):
         """Tier housekeeping at the tick boundary, then frontier prefetch.

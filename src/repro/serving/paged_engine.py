@@ -336,6 +336,7 @@ class PagedWaveEngine:
                "engine_ticks_total": float(s.ticks),
                "engine_hops_total": float(s.total_hops),
                "engine_compactions_total": float(s.compactions),
+               "engine_rerank_rows_total": float(s.rerank_rows),
                "engine_queue_depth": float(len(self.queue)),
                "engine_live_lanes": float(self.pagepool.live_count),
                "engine_lane_capacity": float(self.capacity),
@@ -720,7 +721,8 @@ class PagedWaveEngine:
         with tl.span("retire.results"):
             batch_ids, batch_dists = retire_batch(
                 self.dqf.store, self.dqf._rerank_k, self.cfg.k,
-                ids_b[retiring], dists_b[retiring], self._queries[rl])
+                ids_b[retiring], dists_b[retiring], self._queries[rl],
+                timeline=tl, stats=self.stats)
         # sampled-lane stats transfer once per retiring tick, never per lane
         if any(self._lane_trace[ln] is not None for ln in rl):
             dist_all, term_all = self._fetch(self._state.dist_count,
