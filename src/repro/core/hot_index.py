@@ -47,7 +47,11 @@ class QueryCounter:
 
     @property
     def due(self) -> bool:
-        return self.since_rebuild > self.trigger          # Alg 2 line 5
+        return self.due_after(0)
+
+    def due_after(self, n_queries: int) -> bool:
+        """Whether recording ``n_queries`` more queries trips the trigger."""
+        return self.since_rebuild + n_queries > self.trigger  # Alg 2 line 5
 
     def top(self, n_idx: int,
             alive: np.ndarray | None = None) -> np.ndarray:

@@ -169,6 +169,7 @@ class EngineStats:
     total_hops: int = 0
     compactions: int = 0        # background drain-and-compact cycles
     rerank_rows: int = 0        # rows re-scored in float32 at retirement
+    overlapped_ticks: int = 0   # paged: retired with the next tick running
     # terminal-status tallies keyed by QueryStatus value — the single
     # source for engine_terminal_status_total{status=...}
     terminal: dict = dataclasses.field(default_factory=dict)

@@ -23,7 +23,14 @@ paged attention restructures ragged KV (:mod:`repro.serving.paged`):
   and out continuously and a straggler holds one lane slot, not a wave;
 * with a tiered store, block pins follow the allocator's *pages*: the
   pin set each tick is derived from the page-table-live lanes only, so a
-  retired lane's blocks become evictable the moment its pages free.
+  retired lane's blocks become evictable the moment its pages free;
+* the host's retirement runs while the device works: a step waits for
+  the tick in flight, reads its outputs, copies what the retirement needs
+  and frees the finished lanes, refills them, dispatches the next tick,
+  and only then harvests the answers (``tick.overlap``).  The schedule —
+  which request enters which lane at which tick — is the serial one: a
+  retirement that trips an Alg-2 rebuild or precedes a drained
+  compaction runs before the refill, as it must.
 
 Occupancy (``engine_occupancy_ratio`` = live lanes / lane capacity) is
 published through the same :mod:`repro.obs` registry as the fixed
@@ -36,7 +43,7 @@ from __future__ import annotations
 
 import collections
 import time
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 import jax
@@ -55,6 +62,32 @@ from repro.serving.status import EngineConfig, QueryStatus, shed_victim
 from repro.tenancy import DEFAULT_TENANT
 
 __all__ = ["PagedWaveEngine"]
+
+
+class _InFlight(NamedTuple):
+    """A dispatched tick: its bucket and its device outputs."""
+
+    lanes: np.ndarray           # bucket lane slots, padded
+    n_live: int
+    cap: int                    # the store capacity (sentinel id) it ran on
+    outs: tuple                 # (active, hops, ids, dists) of the bucket
+
+
+class _Retiring(NamedTuple):
+    """One tick's finished lanes, copied before the refill re-uses their
+    slots: per lane its ``_lane_meta``, trace, status and degraded flag,
+    query, pool, hops and (traced lanes only) counters."""
+
+    meta: list
+    traces: list
+    status: list
+    degraded: list
+    queries: np.ndarray
+    ids: np.ndarray
+    dists: np.ndarray
+    hops: np.ndarray
+    dist_evals: Optional[np.ndarray]
+    terminated: Optional[np.ndarray]
 
 
 class PagedWaveEngine:
@@ -154,6 +187,7 @@ class PagedWaveEngine:
         self._lane_degraded = [False] * self.capacity
         self._results: dict = {}
         self._state: Optional[pg.PagedState] = None
+        self._inflight: Optional[_InFlight] = None
         self._queries = np.zeros((self.capacity + 1, self._d), np.float32)
         self._table = None
         self._table_key = None
@@ -286,17 +320,21 @@ class PagedWaveEngine:
         return max(1, int(mq * self._shed_scale))
 
     def step(self) -> None:
-        """Advance one tick; seeds lanes from the queue on first use."""
+        """Advance one tick; seeds lanes from the queue on first use.
+
+        Returns with the next tick in flight whenever lanes are live, and
+        with the answers of the tick it waited for in ``_results``.
+        """
         if self._state is None:
             self._init_wave()
         self._tick()
 
     def run_until_drained(self, max_ticks: int = 10_000) -> dict:
         t0 = self._clock()
+        # with lanes live a tick is in flight, and the first step refills
+        # once it has waited for it, as a refill here would after it
         if self._state is None or not self._any_live():
             self._init_wave()
-        else:
-            self._refill()
         while (self.queue or self._any_live()) \
                 and self.stats.ticks < max_ticks:
             self._tick()
@@ -337,6 +375,7 @@ class PagedWaveEngine:
                "engine_hops_total": float(s.total_hops),
                "engine_compactions_total": float(s.compactions),
                "engine_rerank_rows_total": float(s.rerank_rows),
+               "engine_overlapped_ticks_total": float(s.overlapped_ticks),
                "engine_queue_depth": float(len(self.queue)),
                "engine_live_lanes": float(self.pagepool.live_count),
                "engine_lane_capacity": float(self.capacity),
@@ -368,10 +407,7 @@ class PagedWaveEngine:
         st = self.dqf.store
         if st.epoch == self._epoch:
             return
-        if st.remap_epoch != self._remap_epoch and self._any_live():
-            raise RuntimeError(
-                "store compacted while lanes are in flight — drain the "
-                "engine before calling compact()")
+        self._refuse_remap_in_flight()
         self.dqf._sync_device()
         if self._state is not None:
             if st.remap_epoch != self._remap_epoch:
@@ -386,6 +422,13 @@ class PagedWaveEngine:
         self._cap = st.capacity
         self._epoch = st.epoch
         self._remap_epoch = st.remap_epoch
+
+    def _refuse_remap_in_flight(self):
+        if self.dqf.store.remap_epoch != self._remap_epoch \
+                and self._any_live():
+            raise RuntimeError(
+                "store compacted while lanes are in flight — drain the "
+                "engine before calling compact()")
 
     def _grow_paged(self, old_cap: int, new_cap: int):
         """Re-page live lanes after capacity growth (sentinel id moved).
@@ -641,151 +684,217 @@ class PagedWaveEngine:
 
     def _tick(self):
         tl = self.timeline
+        # before the harvest frees a lane: ids in flight are in the old
+        # id space, so a compaction between steps must find them live
+        self._refuse_remap_in_flight()
         with tl.span("tick", tick=self.stats.ticks):
-            with tl.span("tick.housekeeping"):
-                self._maybe_refresh()
-            with tl.span("tick.tier"):
-                self._tier_begin_tick()
-            lanes_np, pt_np, n_live = self.pagepool.live_bucket(
-                self.min_bucket)
-            if n_live:
-                table = self._bind_table(lanes_np)
-                with tl.span("tick.jit", bucket=len(lanes_np),
-                             live=n_live):
-                    (self._state,
-                     (act, hops_b, ids_b, dists_b)) = self._tick_fn(
-                        self._state, jnp.asarray(lanes_np),
-                        jnp.asarray(pt_np), table,
-                        self.dqf._dev["adj_pad"],
-                        self.dqf._dev["live_pad"])
-                    if tl.enabled:  # make the span cover device time
-                        jax.block_until_ready(self._state)
-                self.stats.ticks += 1
-                (active,) = self._fetch(act)
-                active = active.copy()  # writable: deadlines clear it
-                self._read_hot_stats()
-                now = self._clock()
-                # degraded tier reads: host-fetch batch rows are bucket
-                # rows here — map them through lanes_np to lane slots
-                if self.dqf.store.tiered:
-                    for c in self.dqf.store.tier_caches():
-                        for row in c.take_degraded_rows():
-                            if row < n_live and self._lane_meta[
-                                    lanes_np[row]] is not None:
-                                self._lane_degraded[lanes_np[row]] = True
-                # per-query deadlines: force-expire overdue bucket rows so
-                # they retire this tick with their current best-k
-                expired = [j for j in range(n_live)
-                           if active[j]
-                           and self._lane_meta[lanes_np[j]] is not None
-                           and self._lane_meta[lanes_np[j]][5] is not None
-                           and now >= self._lane_meta[lanes_np[j]][5]]
-                if expired:
-                    lanes_x = lanes_np[expired]
-                    self._state = self._state._replace(
-                        active=self._state.active.at[
-                            jnp.asarray(lanes_x)].set(False))
-                    active[expired] = False
-                    for lane in lanes_x:
-                        self._lane_status[int(lane)] = QueryStatus.DEADLINE
-                retiring = [j for j in range(n_live) if not active[j]
-                            and self._lane_meta[lanes_np[j]] is not None]
-                if retiring:
-                    ids_h, dists_h, hops_h = self._fetch(ids_b, dists_b,
-                                                         hops_b)
-                    with tl.span("tick.retire", retiring=len(retiring)):
-                        self._retire(lanes_np, retiring, ids_h, dists_h,
-                                     hops_h, now)
-            else:
-                self.stats.ticks += 1
+            if self._inflight is None and self._any_live():
+                # first step, or lanes seeded outside a step: no tick of
+                # theirs is running yet
+                self._housekeeping()
+                self._dispatch()
+            ret, now = self._harvest()
+            self._housekeeping()
             if self.auto_compact and not self._draining \
                     and self.dqf.store.should_compact(self.compact_ratio):
                 self._draining = True
-            if self._draining:
-                if not self._any_live():
-                    self._do_compact()
-                    with tl.span("tick.refill"):
-                        self._refill()
-            else:
+            compact = self._draining and not self._any_live()
+            if ret is not None and (compact or self._retire_first(ret)):
+                self._retire(ret, now)
+                ret = None
+            if compact:
+                self._do_compact()
+            if not self._draining:
                 with tl.span("tick.refill"):
                     self._refill()
+            self._dispatch()
+            if ret is not None:
+                if self._inflight is None:      # nothing left to overlap
+                    self._retire(ret, now)
+                else:
+                    with tl.span("tick.overlap", retiring=len(ret.meta)):
+                        self._retire(ret, now)
+                    self.stats.overlapped_ticks += 1
         if self.sentinel is not None:
             self.sentinel.on_tick()
 
-    def _retire(self, lanes_np: np.ndarray, retiring: list,
-                ids_b: np.ndarray, dists_b: np.ndarray,
-                hops_b: np.ndarray, now: float):
-        """Harvest results for retiring bucket rows, then free their lanes."""
+    def _housekeeping(self):
+        with self.timeline.span("tick.housekeeping"):
+            self._maybe_refresh()
+
+    def _dispatch(self):
+        """Tier pins, then issue a tick over the live lanes; no wait."""
         tl = self.timeline
+        with tl.span("tick.tier"):
+            self._tier_begin_tick()
+        lanes_np, pt_np, n_live = self.pagepool.live_bucket(self.min_bucket)
+        if not n_live:
+            return
+        table = self._bind_table(lanes_np)
+        with tl.span("tick.jit", bucket=len(lanes_np), live=n_live):
+            self._state, outs = self._tick_fn(
+                self._state, jnp.asarray(lanes_np), jnp.asarray(pt_np),
+                table, self.dqf._dev["adj_pad"], self.dqf._dev["live_pad"])
+        self._inflight = _InFlight(lanes_np, n_live, self._cap, outs)
+
+    def _harvest(self) -> tuple[Optional[_Retiring], Optional[float]]:
+        """Wait for the tick in flight, count it, read its outputs, expire
+        overdue lanes, and copy out and free the lanes it finished.
+
+        Every read is of that tick's own output state: nothing has been
+        dispatched after it yet.  Returns the finished lanes (None if no
+        lane retires) and the clock reading their latencies end at.
+        """
+        tl = self.timeline
+        fl, self._inflight = self._inflight, None
+        self.stats.ticks += 1
+        if fl is None:
+            return None, None
+        lanes_np, n_live = fl.lanes, fl.n_live
+        with tl.span("tick.jit", bucket=len(lanes_np), live=n_live):
+            if tl.enabled:  # make the span cover device time
+                jax.block_until_ready(self._state)
+        act, hops_b, ids_b, dists_b = fl.outs
+        (active,) = self._fetch(act)
+        active = active.copy()  # writable: deadlines clear it
+        self._read_hot_stats()
+        now = self._clock()
+        # degraded tier reads: host-fetch batch rows are bucket rows here
+        # — map them through lanes_np to lane slots
+        if self.dqf.store.tiered:
+            for c in self.dqf.store.tier_caches():
+                for row in c.take_degraded_rows():
+                    if row < n_live and self._lane_meta[
+                            lanes_np[row]] is not None:
+                        self._lane_degraded[lanes_np[row]] = True
+        # per-query deadlines: force-expire overdue bucket rows so they
+        # retire this tick with their current best-k
+        expired = [j for j in range(n_live)
+                   if active[j]
+                   and self._lane_meta[lanes_np[j]] is not None
+                   and self._lane_meta[lanes_np[j]][5] is not None
+                   and now >= self._lane_meta[lanes_np[j]][5]]
+        if expired:
+            lanes_x = lanes_np[expired]
+            self._state = self._state._replace(
+                active=self._state.active.at[
+                    jnp.asarray(lanes_x)].set(False))
+            active[expired] = False
+            for lane in lanes_x:
+                self._lane_status[int(lane)] = QueryStatus.DEADLINE
+        retiring = [j for j in range(n_live) if not active[j]
+                    and self._lane_meta[lanes_np[j]] is not None]
+        if not retiring:
+            return None, now
+        ids_h, dists_h, hops_h = self._fetch(ids_b, dists_b, hops_b)
+        ids_h = ids_h[retiring]
+        st = self.dqf.store
+        if fl.cap != st.capacity:
+            # the store grew after the dispatch: the tick's empty slots
+            # hold the old sentinel id, now a row id
+            ids_h = np.where(ids_h == fl.cap, st.capacity, ids_h)
         rl = [int(lanes_np[j]) for j in retiring]
-        with tl.span("retire.results"):
-            batch_ids, batch_dists = retire_batch(
-                self.dqf.store, self.dqf._rerank_k, self.cfg.k,
-                ids_b[retiring], dists_b[retiring], self._queries[rl],
-                timeline=tl, stats=self.stats)
+        traces = [self._lane_trace[ln] for ln in rl]
+        evals = term = None
         # sampled-lane stats transfer once per retiring tick, never per lane
-        if any(self._lane_trace[ln] is not None for ln in rl):
+        if any(tr is not None for tr in traces):
             dist_all, term_all = self._fetch(self._state.dist_count,
                                              self._state.terminated)
+            evals, term = dist_all[rl], term_all[rl]
+        # the per-lane half of the retirement that must precede the
+        # refill; the answers' half is the later ``retire.lanes``
         with tl.span("retire.lanes"):
-            for i, j in enumerate(retiring):
-                lane = rl[i]
-                (rid, t_in, t_seed, tenant, gen, _,
-                 seed_tick) = self._lane_meta[lane]
-                ids, dists = batch_ids[i], batch_dists[i]
-                hops = int(hops_b[j])
-                degraded = self._lane_degraded[lane]
-                status = self._lane_status[lane] or (
-                    QueryStatus.DEGRADED if degraded else QueryStatus.OK)
-                self._results[rid] = {"ids": ids, "dists": dists,
-                                      "hops": hops, "tenant": tenant,
-                                      "degraded": bool(degraded),
-                                      "status": status.value}
-                self.stats.completed += 1
-                self.stats.note_terminal(status)
-                if status is QueryStatus.DEADLINE:
-                    self.stats.deadline_hit += 1
-                if degraded:
-                    self.stats.degraded += 1
-                self.stats.total_hops += hops
-                straggled = hops >= self.cfg.max_hops
-                if straggled:
-                    self.stats.straggled += 1
-                service_ms = (now - t_seed) * 1e3
-                self.stats.latencies_ms.append((now - t_in) * 1e3)
-                if self.registry is not None:
-                    self._h_service.observe(service_ms)
-                    self._h_hops.observe(hops)
-                if tl.enabled:
-                    tl.async_span("req.lane", rid, t_seed, now,
-                                  tenant=tenant, status=status.value,
-                                  ticks=self.stats.ticks - seed_tick)
-                tr = self._lane_trace[lane]
-                if tr is not None:
-                    tr.update(
-                        queue_wait_ms=(t_seed - t_in) * 1e3,
-                        service_ms=service_ms,
-                        total_ms=(now - t_in) * 1e3,
-                        full_hops=hops,
-                        full_dist_evals=int(dist_all[lane]),
-                        terminated_early=bool(term_all[lane]),
-                        straggled=straggled,
-                        rerank_k=int(self.dqf._rerank_k),
-                        ticks_in_flight=self.stats.ticks - seed_tick,
-                        top_id=int(ids[0]))
-                    self.traces.add(tr)
-                    self._lane_trace[lane] = None
-                self._lane_meta[lane] = None
-                self._lane_status[lane] = None
-                self._lane_degraded[lane] = False
-                t = self.dqf.tenants.get(tenant) \
-                    if tenant in self.dqf.tenants else None
-                if t is not None and t.gen == gen:
-                    self.dqf.record(ids[None, :], tenant=tenant)
-                    if t.counter.due:  # Alg 2's trigger
-                        with tl.span("hot.rebuild", tenant=tenant) as sp:
-                            hot = self.dqf.rebuild_hot(tenant=tenant)
-                            if tl.enabled:
-                                sp.args.update(hot_rows=hot.size,
-                                               version=hot.version)
+            ret = _Retiring(
+                meta=[self._lane_meta[ln] for ln in rl],
+                traces=traces, status=[self._lane_status[ln] for ln in rl],
+                degraded=[self._lane_degraded[ln] for ln in rl],
+                queries=self._queries[rl], ids=ids_h,
+                dists=dists_h[retiring], hops=hops_h[retiring],
+                dist_evals=evals, terminated=term)
+            for ln in rl:
+                self._lane_meta[ln] = None
+                self._lane_trace[ln] = None
+                self._lane_status[ln] = None
+                self._lane_degraded[ln] = False
             self.pagepool.free(rl)
+        return ret, now
+
+    def _retire_first(self, ret: _Retiring) -> bool:
+        """Whether ``ret``'s answers trip some tenant's Alg-2 trigger.
+
+        The rebuild they cause must land before the refill, so that every
+        admission sees the hot index it would in a serial loop.
+        """
+        reg = self.dqf.tenants
+        for (name, gen), n in collections.Counter(
+                (m[3], m[4]) for m in ret.meta).items():
+            t = reg.get(name) if name in reg else None
+            if t is not None and t.gen == gen and t.counter.due_after(n):
+                return True
+        return False
+
+    def _retire(self, ret: _Retiring, now: float):
+        """Results, stats and Alg-2 feedback of retiring lanes, their
+        latencies ending at ``now`` (``tick.retire``)."""
+        tl = self.timeline
+        with tl.span("tick.retire", retiring=len(ret.meta)):
+            with tl.span("retire.results"):
+                batch_ids, batch_dists = retire_batch(
+                    self.dqf.store, self.dqf._rerank_k, self.cfg.k,
+                    ret.ids, ret.dists, ret.queries, timeline=tl,
+                    stats=self.stats)
+            with tl.span("retire.lanes"):
+                for i, (rid, t_in, t_seed, tenant, gen, _,
+                        seed_tick) in enumerate(ret.meta):
+                    ids, dists = batch_ids[i], batch_dists[i]
+                    hops = int(ret.hops[i])
+                    degraded = ret.degraded[i]
+                    status = ret.status[i] or (
+                        QueryStatus.DEGRADED if degraded else QueryStatus.OK)
+                    self._results[rid] = {"ids": ids, "dists": dists,
+                                          "hops": hops, "tenant": tenant,
+                                          "degraded": bool(degraded),
+                                          "status": status.value}
+                    self.stats.completed += 1
+                    self.stats.note_terminal(status)
+                    if status is QueryStatus.DEADLINE:
+                        self.stats.deadline_hit += 1
+                    if degraded:
+                        self.stats.degraded += 1
+                    self.stats.total_hops += hops
+                    straggled = hops >= self.cfg.max_hops
+                    if straggled:
+                        self.stats.straggled += 1
+                    service_ms = (now - t_seed) * 1e3
+                    self.stats.latencies_ms.append((now - t_in) * 1e3)
+                    if self.registry is not None:
+                        self._h_service.observe(service_ms)
+                        self._h_hops.observe(hops)
+                    if tl.enabled:
+                        tl.async_span("req.lane", rid, t_seed, now,
+                                      tenant=tenant, status=status.value,
+                                      ticks=self.stats.ticks - seed_tick)
+                    tr = ret.traces[i]
+                    if tr is not None:
+                        tr.update(
+                            queue_wait_ms=(t_seed - t_in) * 1e3,
+                            service_ms=service_ms,
+                            total_ms=(now - t_in) * 1e3,
+                            full_hops=hops,
+                            full_dist_evals=int(ret.dist_evals[i]),
+                            terminated_early=bool(ret.terminated[i]),
+                            straggled=straggled,
+                            rerank_k=int(self.dqf._rerank_k),
+                            ticks_in_flight=self.stats.ticks - seed_tick,
+                            top_id=int(ids[0]))
+                        self.traces.add(tr)
+                    t = self.dqf.tenants.get(tenant) \
+                        if tenant in self.dqf.tenants else None
+                    if t is not None and t.gen == gen:
+                        self.dqf.record(ids[None, :], tenant=tenant)
+                        if t.counter.due:  # Alg 2's trigger
+                            with tl.span("hot.rebuild", tenant=tenant) as sp:
+                                hot = self.dqf.rebuild_hot(tenant=tenant)
+                                if tl.enabled:
+                                    sp.args.update(hot_rows=hot.size,
+                                                   version=hot.version)

@@ -4,6 +4,11 @@ Contracts under test:
 
 * the tick's children nest inside ``tick`` (and inside ``tick.retire`` /
   ``tick.refill``) under exactly the names the benchmark's readers use;
+  the tick's reads come before its refill, the next tick's dispatch after
+  it, and the retirement last, inside ``tick.overlap`` while that next
+  tick runs (or right under ``tick`` when it must precede the refill or
+  no tick follows); the finished lanes' copy and free, which must precede
+  the refill, is a ``retire.lanes`` right under ``tick``;
 * one ``req.queued`` and one ``req.lane`` "b"/"e" pair per served
   request, as long as the trace log's queue wait and service time;
 * a ``hot.rebuild`` span exactly when an Alg-2 rebuild runs;
@@ -26,9 +31,11 @@ from repro.obs import ObsConfig
 from repro.serving.paged_engine import PagedWaveEngine
 
 PARENT = {"tick.housekeeping": "tick", "tick.tier": "tick",
-          "tick.jit": "tick", "tick.fetch": ("tick", "tick.retire"),
-          "tick.retire": "tick", "tick.refill": "tick",
-          "retire.results": "tick.retire", "retire.lanes": "tick.retire",
+          "tick.jit": "tick", "tick.fetch": "tick",
+          "tick.overlap": "tick", "tick.retire": ("tick.overlap", "tick"),
+          "tick.refill": "tick",
+          "retire.results": "tick.retire",
+          "retire.lanes": ("tick.retire", "tick"),
           "hot.rebuild": "retire.lanes", "refill.queue": "tick.refill",
           "refill.admit": "tick.refill", "refill.lanes": "tick.refill"}
 TIMING_KEYS = {"queue_wait_ms", "service_ms", "total_ms"}
@@ -84,6 +91,22 @@ def test_tick_children_nest_under_their_names(built_dqf):
         assert any(_inside(e, p) for p in _x(evs) if p["name"] in parents
                    and p is not e), e
         assert sum(_inside(e, t) for t in ticks) == 1, e
+    # the retirement overlaps the next tick: it starts after the refill
+    # and the dispatch that follows it, inside the same tick
+    overlaps = _x(evs, "tick.overlap")
+    assert overlaps
+    for o in overlaps:
+        (t,) = [t for t in ticks if _inside(o, t)]
+        (r,) = [r for r in _x(evs, "tick.refill") if _inside(r, t)]
+        assert any(r["ts"] + r["dur"] <= j["ts"]
+                   and j["ts"] + j["dur"] <= o["ts"]
+                   for j in _x(evs, "tick.jit") if _inside(j, t)), o
+        # the lanes it retires were freed before that refill, under a
+        # ``retire.lanes`` of the tick's own
+        assert any(f["ts"] + f["dur"] <= r["ts"]
+                   and not any(_inside(f, p)
+                               for p in _x(evs, "tick.retire"))
+                   for f in _x(evs, "retire.lanes") if _inside(f, t)), o
     fetches = _x(evs, "tick.fetch")
     assert all(f["args"]["arrays"] >= 1 and f["args"]["bytes"] > 0
                for f in fetches)

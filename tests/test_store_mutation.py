@@ -294,3 +294,33 @@ def test_engine_refuses_compact_in_flight(churn_world):
     dqf.compact()
     with pytest.raises(RuntimeError, match="drain"):
         eng._tick()
+
+
+@pytest.mark.parametrize("tick_hops", [2, 100], ids=["some_live",
+                                                     "all_retire"])
+def test_paged_engine_refuses_compact_in_flight(tick_hops):
+    """A compaction between steps, with a tick in flight, is refused
+    before that tick's lanes are freed: also when every lane of it
+    finishes there (``tick_hops`` = ``max_hops``), and without losing an
+    answer."""
+    from repro.serving.paged_engine import PagedWaveEngine
+
+    x = make_clustered(n=600, d=16, clusters=8, seed=13)
+    dqf = DQF(_small_cfg()).build(x)
+    wl = ZipfWorkload(x, seed=14)
+    _, t = wl.sample(1000, with_targets=True)
+    dqf.counter.record(t)
+    dqf.rebuild_hot()
+    eng = PagedWaveEngine(dqf, capacity=8, tick_hops=tick_hops,
+                          min_bucket=4)
+    eng.submit(wl.sample(16))
+    eng.step()
+    assert eng._inflight is not None and eng.pagepool.live_count == 8
+    dqf.delete(dqf.store.to_external(np.arange(0, 60, 3)))
+    dqf.compact()
+    ticks, answered = eng.stats.ticks, dict(eng._results)
+    with pytest.raises(RuntimeError, match="drain"):
+        eng.step()
+    assert eng._inflight is not None and eng.pagepool.live_count == 8
+    assert eng.stats.ticks == ticks and set(eng._results) == set(answered)
+    assert len(answered) + 8 + len(eng.queue) == 16
